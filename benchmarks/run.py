@@ -37,6 +37,8 @@ def main(argv=None):
     args = ap.parse_args(argv)
     quick = not args.full
     only = set(args.only.split(",")) if args.only else None
+    from repro.utils import hw
+    hw.enable_compile_cache()
 
     from benchmarks import (table1_noniid, fig3_drift, table3_llm,
                             table4_beta, table5_ablation, table6_comm,

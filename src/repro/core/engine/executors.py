@@ -34,7 +34,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax.sharding import AxisType
 
 BACKENDS = ("vmap", "shard_map", "chunked", "sharded")
 
@@ -63,7 +63,8 @@ def _leading_dim(args) -> int:
 def _default_mesh():
     # the local device set is fixed for the process lifetime, so the mesh
     # is too — rebuilding it per executor call only burned host time
-    return jax.make_mesh((len(jax.devices()),), ("data",))
+    return jax.make_mesh((len(jax.devices()),), ("data",),
+                         axis_types=(AxisType.Auto,))
 
 
 def _chunked_run(one_client, chunk_size: int, *args):
@@ -105,9 +106,9 @@ def _make_shard_runner(cfg: ExecutorConfig, shard_body_of):
                 f"cohort size {s} not divisible by the client-axis "
                 f"extent {n} (mesh axes {axes}) — pad the cohort or "
                 f"use the 'chunked' executor")
-        return shard_map(shard_body_of(one_client), mesh=mesh,
-                         in_specs=(spec,) * len(args), out_specs=spec,
-                         check_rep=False)(*args)
+        return jax.shard_map(shard_body_of(one_client), mesh=mesh,
+                             in_specs=(spec,) * len(args), out_specs=spec,
+                             check_vma=False)(*args)
     return run
 
 

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import time
 
 import numpy as np
 import jax
@@ -19,11 +20,11 @@ import jax.numpy as jnp
 from repro import configs
 from repro.data import make_lm_corpus
 from repro.data.synth import lm_batches
-from repro.fed import FedConfig, FederatedExperiment
 from repro.models import model as M
+from repro.utils import hw
 
 
-def main(argv=None):
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama-60m")
     ap.add_argument("--reduced", action="store_true")
@@ -44,15 +45,19 @@ def main(argv=None):
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="append a structured JSONL round trace (spans, "
                          "metrics, telemetry) to PATH")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def build(args, **fed_overrides):
+    """(experiment, model config) for parsed ``args``: the synthetic non-IID
+    LM task through ``repro.api.build_experiment``; ``fed_overrides`` are
+    further ``FedConfig`` fields (``theta_codec=``, ``executor=``, ...)."""
+    from repro.api import build_experiment
 
     cfg = (configs.get_reduced(args.arch)
            if args.reduced else configs.get_config(args.arch))
     cfg = cfg.replace(dtype="float32")
     params = M.init_params(cfg, jax.random.key(args.seed))
-    n_par = M.num_params(cfg)
-    print(f"arch={cfg.name} params={n_par/1e6:.1f}M "
-          f"algorithm={args.algorithm}")
 
     streams = make_lm_corpus(args.clients, 200_000, vocab=cfg.vocab_size,
                              hetero=args.hetero, seed=args.seed)
@@ -77,11 +82,34 @@ def main(argv=None):
         return {"tokens": jnp.asarray(w[:, :-1]),
                 "labels": jnp.asarray(w[:, 1:])}
 
-    fed = FedConfig(algorithm=args.algorithm, n_clients=args.clients,
-                    participation=args.participation, rounds=args.rounds,
-                    local_steps=args.local_steps, lr=args.lr, beta=args.beta,
-                    seed=args.seed)
-    exp = FederatedExperiment(fed, params, loss_fn, batch_fn, eval_fn)
+    fed = dict(n_clients=args.clients, participation=args.participation,
+               rounds=args.rounds, local_steps=args.local_steps, lr=args.lr,
+               beta=args.beta, seed=args.seed)
+    fed.update(fed_overrides)
+    exp = build_experiment(args.algorithm, params=params, loss_fn=loss_fn,
+                           client_batch_fn=batch_fn, eval_fn=eval_fn, **fed)
+    return exp, cfg
+
+
+def timed_round(exp):
+    """One round; ``round_s`` is host time until the new params are ready
+    (the first round includes compiling)."""
+    t0 = time.perf_counter()
+    rec = exp.run_round()
+    jax.block_until_ready(exp.server.params)
+    rec["round_s"] = time.perf_counter() - t0
+    return rec
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    dev = jax.devices()[0]
+    print(f"platform={dev.platform} device_kind={dev.device_kind} "
+          f"devices={len(jax.devices())}")
+    hw.enable_compile_cache()
+    exp, cfg = build(args)
+    print(f"arch={cfg.name} params={M.num_params(cfg)/1e6:.1f}M "
+          f"algorithm={args.algorithm}")
     mgr = None
     if args.checkpoint_dir:
         from repro.checkpoint import CheckpointManager
@@ -99,8 +127,8 @@ def main(argv=None):
                 pass
         exp.tracer = Tracer.from_state(state, sinks=(sink,))
     hist = []
-    for r in range(fed.rounds):
-        rec = exp.run_round()
+    for r in range(args.rounds):
+        rec = timed_round(exp)
         hist.append(rec)
         exp.log_round(rec, r)
         if mgr and (r + 1) % args.checkpoint_every == 0:

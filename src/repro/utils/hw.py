@@ -7,10 +7,19 @@ and the kernel profiling harness all resolve their ``use_pallas`` /
 ``interpret`` defaults here, so an accelerator host never silently runs
 the reference path just because a caller left the knobs at their CPU
 defaults.
+
+It also owns where compiled programs are cached between processes
+(``enable_compile_cache``), so every entry point shares one cache.
 """
 from __future__ import annotations
 
+import os
+from pathlib import Path
+
 import jax
+
+# <checkout>/.jax_cache: a fixed path, because the cache key includes it
+CHECKOUT_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
 
 
 def on_tpu() -> bool:
@@ -36,3 +45,15 @@ def resolve_interpret(interpret=None) -> bool:
 def resolve_use_pallas(use_pallas=None) -> bool:
     """``None`` means auto; explicit booleans pass through."""
     return default_use_pallas() if use_pallas is None else bool(use_pallas)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins when set; otherwise the cache lives
+    at ``<checkout>/.jax_cache``.  Entry points call this from ``main()``,
+    never at import, so tests and library users keep JAX's own default.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or CHECKOUT_CACHE_DIR
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -16,11 +16,13 @@ SCRIPT = textwrap.dedent("""
     import json
     import jax
     import jax.numpy as jnp
+    from jax.sharding import AxisType
     from repro import configs
     from repro.launch import dryrun
     from repro.launch.specs import InputShape
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     out = {}
     for arch, shape_name, kind in [
         ("smollm-360m", "train_4k", "train"),

@@ -477,11 +477,12 @@ _SHARDED_SCRIPT = textwrap.dedent("""
     sys.path.insert(0, {src!r})
     import numpy as np
     import jax, jax.numpy as jnp
+    from jax.sharding import AxisType
     from repro.core.engine.executors import ExecutorConfig, \\
         make_cohort_executor
 
     assert len(jax.devices()) == 4, jax.devices()
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
 
     def one_client(batch):
         return {{"out": batch * 2.0, "s": jnp.tanh(batch @ batch.T).sum()}}
