@@ -1,19 +1,13 @@
 """Roofline report generator.
 
-Two modes:
+Reads dry-run JSONL records and renders the per-(arch x shape x mesh) table
+for EXPERIMENTS.md §Roofline:
 
-  (default)   reads dry-run JSONL records and renders the per-(arch x
-              shape x mesh) table for EXPERIMENTS.md §Roofline:
-                PYTHONPATH=src python -m benchmarks.roofline \\
-                    results/dryrun_baseline.jsonl
-  --kernels   *measures* the kernel triads (soap_rotate, qblock, ns_ortho,
-              sophia_update, fused_agg) through the observability profiling
-              hooks
-              (``repro.obs.profiling``) and renders achieved GFLOP/s and
-              GB/s per (kernel, impl, shape) — the measured points to place
-              against the analytic roofline above:
-                PYTHONPATH=src python -m benchmarks.roofline --kernels \\
-                    --shapes 256x256,512x512
+    PYTHONPATH=src python -m benchmarks.roofline \\
+        results/dryrun_baseline.jsonl
+
+Kernel roofline shares on the chip come from the device trace
+(``benchmarks/chip``).
 """
 from __future__ import annotations
 
@@ -75,26 +69,6 @@ def table(recs, mesh="pod"):
     return "\n".join(out)
 
 
-def kernel_table(records):
-    hdr = ("| kernel | impl | shape | us/call | GFLOP/s | GB/s | backend |")
-    sep = "|" + "---|" * 7
-    out = [hdr, sep]
-    for r in records:
-        shape = "x".join(str(d) for d in r["shape"])
-        out.append(
-            f"| {r['kernel']} | {r['impl']} | {shape} "
-            f"| {r['us_per_call']:.1f} | {r['gflops_s']:.2f} "
-            f"| {r['gbps']:.2f} | {r['backend']} |")
-    return "\n".join(out)
-
-
-def run_kernels(shapes, iters=5, kernels=None):
-    from repro.obs import profile_kernels
-    records = profile_kernels(shapes=shapes, iters=iters, kernels=kernels)
-    print(kernel_table(records))
-    return records
-
-
 def report(path):
     recs = load(path)
     print(table(recs, "pod"))
@@ -111,19 +85,8 @@ def report(path):
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("path", nargs="?", default="results/dryrun_baseline.jsonl",
-                    help="dry-run JSONL records (report mode)")
-    ap.add_argument("--kernels", action="store_true",
-                    help="profile the kernel triads instead of reading "
-                         "dry-run records")
-    ap.add_argument("--shapes", default="256x256",
-                    help="comma-separated NxM shapes for --kernels")
-    ap.add_argument("--iters", type=int, default=5)
+                    help="dry-run JSONL records")
     args = ap.parse_args(argv)
-    if args.kernels:
-        shapes = tuple(tuple(int(d) for d in s.split("x"))
-                       for s in args.shapes.split(","))
-        run_kernels(shapes, iters=args.iters)
-        return 0
     return report(args.path)
 
 

@@ -318,11 +318,12 @@ def make_wire_client_step(spec: AlgorithmSpec, local_fn: Callable,
             batch_i=batch_i, key_i=key_i)
         if transport is None:
             return delta, theta_out, algo_out, loss
-        dmsg, decoded, new_residual = T.encode_with_feedback(
-            transport.delta, delta, residual)
+        with jax.named_scope("upload_encode"):
+            dmsg, decoded, new_residual = T.encode_with_feedback(
+                transport.delta, delta, residual)
+            tmsg = (transport.theta.encode(theta_out) if encode_theta
+                    else theta_out)
         dchan = (dmsg, decoded) if (ef_active and not fused) else dmsg
-        tmsg = (transport.theta.encode(theta_out) if encode_theta
-                else theta_out)
         if ef_active:
             out = ((algo_out, new_residual) if has_algo_state
                    else new_residual)
@@ -520,57 +521,59 @@ def build_round_fn(
             one_client, cohort, batches, keys)
         step = None
         weights = jnp.ones((s,), jnp.float32)
-        if fused:
-            # fused wire path: the stacked messages reduce straight into
-            # the weighted sums (Codec.accumulate); byte counts are static
-            # shape math over those same structures, recorded as the exact
-            # total + cohort size (no truncating division)
-            up_bytes = T.wire_bytes(deltas)
-            if encode_theta:
-                up_bytes += T.wire_bytes(thetas)
-            wire_cell["total"] = up_bytes
-            wire_cell["cohort"] = s
-            new_params, new_theta, new_g, agg, aux = aggregate_wire(
-                params, theta, g_global, deltas, weights, agg_cfg,
-                transport, tmsgs=thetas if encode_theta else None,
-                thetas=None if encode_theta else thetas,
-                need_thetas=telemetry)
-            deltas, thetas, step = None, aux["thetas"], aux["step"]
-        else:
-            if transport is not None:
-                # decode-then-aggregate fallback: mixing hooks consume the
-                # decoded cohort, so it must materialize here
-                if ef_active:
-                    dmsgs, deltas = deltas
-                    up_bytes = T.wire_bytes(dmsgs)
-                else:
-                    up_bytes = T.wire_bytes(deltas)
-                    deltas = jax.vmap(transport.delta.decode)(deltas)
+        with jax.named_scope("flush"):
+            if fused:
+                # fused wire path: the stacked messages reduce straight into
+                # the weighted sums (Codec.accumulate); byte counts are static
+                # shape math over those same structures, recorded as the exact
+                # total + cohort size (no truncating division)
+                up_bytes = T.wire_bytes(deltas)
                 if encode_theta:
                     up_bytes += T.wire_bytes(thetas)
-                    thetas = jax.vmap(transport.theta.decode)(thetas)
                 wire_cell["total"] = up_bytes
                 wire_cell["cohort"] = s
-            elif compress_fn is not None and thetas is not None:
-                # legacy path: clients upload compressed Theta; server
-                # aggregates the decoded reconstruction (Table 6 trade-off)
-                thetas = compress_fn(thetas)
-            if spec.mixing is not None:
-                weights = spec.mixing(deltas, thetas)
-            new_params, new_theta, new_g, agg = aggregate(
-                params, theta, g_global, deltas, thetas, weights, agg_cfg)
-        new_cstate = (state_proto.server_update(cstate, cohort, outs,
-                                                n_clients)
-                      if state_proto is not None else cstate)
-        new_ctrl = update_controller(ctrl, agg["norm_drift"],
-                                     agg["freshness"])
-        metrics = dict(agg, loss=jnp.mean(losses), beta=ctrl.beta)
-        if telemetry:
-            from repro.obs import telemetry as obs_telemetry
-            metrics["telemetry"] = obs_telemetry.collect(
-                deltas=deltas, step=step, thetas=thetas, weights=weights,
-                g_global=g_global, ctrl=ctrl, new_ctrl=new_ctrl,
-                agg_metrics=agg)
+                new_params, new_theta, new_g, agg, aux = aggregate_wire(
+                    params, theta, g_global, deltas, weights, agg_cfg,
+                    transport, tmsgs=thetas if encode_theta else None,
+                    thetas=None if encode_theta else thetas,
+                    need_thetas=telemetry)
+                deltas, thetas, step = None, aux["thetas"], aux["step"]
+            else:
+                if transport is not None:
+                    # decode-then-aggregate fallback: mixing hooks consume the
+                    # decoded cohort, so it must materialize here
+                    if ef_active:
+                        dmsgs, deltas = deltas
+                        up_bytes = T.wire_bytes(dmsgs)
+                    else:
+                        up_bytes = T.wire_bytes(deltas)
+                        deltas = jax.vmap(transport.delta.decode)(deltas)
+                    if encode_theta:
+                        up_bytes += T.wire_bytes(thetas)
+                        thetas = jax.vmap(transport.theta.decode)(thetas)
+                    wire_cell["total"] = up_bytes
+                    wire_cell["cohort"] = s
+                elif compress_fn is not None and thetas is not None:
+                    # legacy path: clients upload compressed Theta; server
+                    # aggregates the decoded reconstruction (Table 6 trade-off)
+                    thetas = compress_fn(thetas)
+                if spec.mixing is not None:
+                    weights = spec.mixing(deltas, thetas)
+                new_params, new_theta, new_g, agg = aggregate(
+                    params, theta, g_global, deltas, thetas, weights, agg_cfg)
+        with jax.named_scope("server_update"):
+            new_cstate = (state_proto.server_update(cstate, cohort, outs,
+                                                    n_clients)
+                          if state_proto is not None else cstate)
+            new_ctrl = update_controller(ctrl, agg["norm_drift"],
+                                         agg["freshness"])
+            metrics = dict(agg, loss=jnp.mean(losses), beta=ctrl.beta)
+            if telemetry:
+                from repro.obs import telemetry as obs_telemetry
+                metrics["telemetry"] = obs_telemetry.collect(
+                    deltas=deltas, step=step, thetas=thetas,
+                    weights=weights, g_global=g_global, ctrl=ctrl,
+                    new_ctrl=new_ctrl, agg_metrics=agg)
         return new_params, new_theta, new_g, new_ctrl, new_cstate, metrics
 
     if jit:

@@ -71,23 +71,27 @@ def client_round(
     def step(carry, inp):
         x, st, k = carry
         batch, key = inp
-        loss, grads = jax.value_and_grad(loss_fn)(x, batch)
+        with jax.named_scope("local_model"):
+            loss, grads = jax.value_and_grad(loss_fn)(x, batch)
         extras = None
         if opt.needs_hessian:
             gate = (k % run.hessian_freq) == 0
-            est = jax.lax.cond(
-                gate,
-                lambda: hutchinson_estimate(loss_fn, x, batch, key),
-                lambda: jax.tree.map(
-                    lambda p: jnp.zeros(p.shape, jnp.float32), x),
-            )
+            with jax.named_scope("precond_refresh"):
+                est = jax.lax.cond(
+                    gate,
+                    lambda: hutchinson_estimate(loss_fn, x, batch, key),
+                    lambda: jax.tree.map(
+                        lambda p: jnp.zeros(p.shape, jnp.float32), x),
+                )
             extras = {"h_est": est, "h_gate": gate}
-        direction, st = opt.update(grads, st, x, k, extras)
+        with jax.named_scope("local_precond"):
+            direction, st = opt.update(grads, st, x, k, extras)
         # Eq. 9: x <- x - lr [ (1-beta) P_Theta(g) + beta g_G ]
         def mix(d, gg, p):
             upd = (1.0 - beta) * d + beta * gg
             return (p.astype(jnp.float32) - run.lr * upd).astype(p.dtype)
-        x = jax.tree.map(mix, direction, g_global, x)
+        with jax.named_scope("local_correction"):
+            x = jax.tree.map(mix, direction, g_global, x)
         return (x, st, k + 1), loss
 
     keys = jax.random.split(rng, run.local_steps)

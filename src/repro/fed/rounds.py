@@ -39,8 +39,11 @@ from repro.core.transport import (
     Transport, validate_codec_spec, validate_wire_dtype,
 )
 from repro.fed.base import FedExperiment
+from repro.obs.telemetry import telemetry_dict
 from repro.utils import hw
-from repro.fed.staging import StagingBuffers, stage_cohort_batches
+from repro.fed.staging import (
+    StagingBuffers, produce_cohort_batches, stack_cohort_batches,
+)
 
 RUNTIMES = ("sync", "async")
 
@@ -325,10 +328,12 @@ class FederatedExperiment(FedExperiment):
         return self.rng.choice(self.fed.n_clients, size=s, replace=False)
 
     def _stage_batches(self, cohort):
-        """Stack per-client, per-step batches -> leading (S, K, ...) axes."""
-        return stage_cohort_batches(self.client_batch_fn, cohort,
-                                    self.fed.local_steps, self.rng,
-                                    buffers=self._staging_buffers)
+        """Stack per-client, per-step batches -> leading (S, K, ...) axes;
+        the per-client batch production is the ``stage_batches`` span."""
+        with self.tracer.span("stage_batches", round=self.server.round + 1):
+            per_client = produce_cohort_batches(
+                self.client_batch_fn, cohort, self.fed.local_steps, self.rng)
+        return stack_cohort_batches(per_client, self._staging_buffers)
 
     def _stage_population(self, round_index: int):
         """One population round's inputs: streamed cohort, fold_in-derived
@@ -384,7 +389,10 @@ class FederatedExperiment(FedExperiment):
                     jax.block_until_ready(metrics)
         tele = metrics.pop("telemetry", None)
         self.last_telemetry = tele
-        rec = {k: float(v) for k, v in metrics.items()}
+        with t.span("readback", round=rnum):
+            rec = {k: float(v) for k, v in metrics.items()}
+            tele_rec = (telemetry_dict(tele)
+                        if t.enabled and tele is not None else None)
         rec["round"] = self.server.round
         if self.state_store is not None:
             rec.update(state_resident=self.state_store.resident,
@@ -396,10 +404,7 @@ class FederatedExperiment(FedExperiment):
                 rec.update({k: float(v) for k, v in
                             self.eval_fn(self.server.params).items()})
         if t.enabled:
-            from repro.obs.telemetry import telemetry_dict
-            t.round_event(rec["round"], rec,
-                          telemetry=telemetry_dict(tele) if tele is not None
-                          else None)
+            t.round_event(rec["round"], rec, telemetry=tele_rec)
         self.history.append(rec)
         return rec
 

@@ -124,6 +124,26 @@ class StagingBuffers:
         jax.tree.map(lambda b, r: b.__setitem__(i, np.asarray(r)), buf, row)
 
 
+def produce_cohort_batches(client_batch_fn, cohort, local_steps: int, rng):
+    """Each cohort client's K per-step batches, stacked to (K, ...) pytrees
+    (the per-client half of ``stage_cohort_batches``)."""
+    return [_stack_steps(client_batch_fn, cid, local_steps, rng)
+            for cid in cohort]
+
+
+def stack_cohort_batches(per_client, buffers: StagingBuffers | None = None):
+    """Per-client (K, ...) pytrees stacked to leading (S, K, ...) axes on
+    the device (the cohort half of ``stage_cohort_batches``)."""
+    stack = _stacker(per_client[0])
+    if buffers is not None and stack is np.stack:
+        buf = buffers.get("cohort", len(per_client), per_client[0])
+        for i, row in enumerate(per_client):
+            StagingBuffers.fill_row(buf, i, row)
+        return jax.tree.map(jnp.asarray, buf)
+    stacked = jax.tree.map(lambda *xs: stack(xs), *per_client)
+    return jax.tree.map(jnp.asarray, stacked)
+
+
 def stage_cohort_batches(client_batch_fn, cohort, local_steps: int, rng,
                          buffers: StagingBuffers | None = None):
     """A cohort's batches, stacked to leading (S, K, ...) axes.
@@ -134,13 +154,6 @@ def stage_cohort_batches(client_batch_fn, cohort, local_steps: int, rng,
     Device-side batch fns keep the ``jnp.stack`` path: their leaves are
     already on device and a host bounce would add S*K transfers.
     """
-    per_client = [_stack_steps(client_batch_fn, cid, local_steps, rng)
-                  for cid in cohort]
-    stack = _stacker(per_client[0])
-    if buffers is not None and stack is np.stack:
-        buf = buffers.get("cohort", len(per_client), per_client[0])
-        for i, row in enumerate(per_client):
-            StagingBuffers.fill_row(buf, i, row)
-        return jax.tree.map(jnp.asarray, buf)
-    stacked = jax.tree.map(lambda *xs: stack(xs), *per_client)
-    return jax.tree.map(jnp.asarray, stacked)
+    return stack_cohort_batches(
+        produce_cohort_batches(client_batch_fn, cohort, local_steps, rng),
+        buffers)

@@ -1,6 +1,6 @@
-"""First-class observability: jit-pure drift telemetry, round-trace spans,
-pluggable sinks, kernel profiling hooks, and the BENCH_*.json perf
-trajectory.
+"""First-class observability: jit-pure drift telemetry, round-trace spans
+(on the profiler's clock when enabled), the round program's named scopes,
+pluggable sinks, and the BENCH_*.json perf trajectory.
 
 Attach a trace to any experiment (both runtimes):
 
@@ -29,14 +29,14 @@ from repro.obs.telemetry import (  # noqa: F401
     staleness_histogram, telemetry_dict,
 )
 from repro.obs.trace import (  # noqa: F401
-    NULL_TRACER, PHASES, Tracer, validate_event, validate_jsonl,
+    NULL_TRACER, PHASES, SCOPES, Tracer, validate_event, validate_jsonl,
 )
 
 __all__ = [
     "BENCH_SCHEMA_VERSION", "CsvSink", "JsonlSink", "MemorySink",
-    "NULL_TRACER", "PHASES", "STALENESS_BINS", "Sink", "StdoutRoundSink",
-    "Telemetry", "Tracer", "attach", "client_geom_dist", "collect",
-    "format_metric", "make_bench", "profile_kernels", "read_bench",
+    "NULL_TRACER", "PHASES", "SCOPES", "STALENESS_BINS", "Sink",
+    "StdoutRoundSink", "Telemetry", "Tracer", "attach", "client_geom_dist",
+    "collect", "format_metric", "make_bench", "read_bench",
     "staleness_histogram", "telemetry_dict", "validate_bench",
     "validate_event", "validate_jsonl", "write_bench",
 ]
@@ -51,10 +51,3 @@ def attach(exp, *sinks, run_id=None) -> Tracer:
     tracer = Tracer(sinks=sinks, run_id=run_id)
     exp.tracer = tracer
     return tracer
-
-
-def profile_kernels(*args, **kwargs):
-    """Lazy re-export of ``repro.obs.profiling.profile_kernels`` (imports
-    the kernel packages only when profiling is actually requested)."""
-    from repro.obs.profiling import profile_kernels as _pk
-    return _pk(*args, **kwargs)

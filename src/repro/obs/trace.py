@@ -28,6 +28,33 @@ reduce to a no-op context manager and nothing is emitted, but the
 round/span counters still advance so checkpoints can persist trace
 continuity (``state``/``from_state`` — a restored run appends to the same
 JSONL trace instead of restarting its numbering).
+
+On the profiler's clock.  An enabled tracer also opens a
+``jax.profiler.TraceAnnotation`` named ``repro.<phase>`` around each span,
+so under ``jax.profiler.start_trace`` the program's own phases land on the
+trace's host plane, on the clock of the device ops.  The disabled tracer
+reads no clock and opens no annotation.
+
+``PHASES`` are the host phases.  ``readback`` is the round's device-to-host
+sync: the ``float`` of each round metric and the telemetry dict.
+
+``SCOPES`` are the ``jax.named_scope`` names inside the round program, one
+fixed vocabulary used at every site (trace-time metadata: they appear in
+each HLO op's ``op_name`` and cost nothing at run time):
+
+  local_model       forward and backward of the client's loss
+                    (``core.client.client_round``)
+  local_precond     the local optimizer's update (SOAP: factor EMAs,
+                    rotations, Adam in the eigenbasis)
+  precond_refresh   the curvature refresh: SOAP's eigenbasis refresh
+                    (nested in ``local_precond``), Sophia's Hutchinson
+                    estimate
+  local_correction  FedPAC's correction (Eq. 9)
+  upload_encode     the delta and Theta upload codecs
+                    (``core.algorithms.make_wire_client_step``)
+  flush             aggregation of the uploads and Theta
+                    (``engine.aggregate_wire`` / ``aggregate``)
+  server_update     client-state scatter, geometry controller, telemetry
 """
 from __future__ import annotations
 
@@ -36,19 +63,28 @@ import time
 import uuid
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 EVENT_TYPES = ("run_start", "span", "round", "client_dropped",
                "client_join", "client_leave", "anytime_eval")
 DROP_REASONS = ("dropout", "max_staleness", "client_left", "algo_swap")
 
 # canonical phase names; the sync runtime fuses local update, wire encode
 # and aggregation into one jitted call traced as a single "update" span.
-# Population staging splits into "stage_batches" + "state_acquire"; the
+# Staging holds a "stage_batches" child (the per-client batches) and, for
+# population runs, "state_acquire"; "readback" follows "update"; the
 # chunk-streaming pipeline (fed.pipeline) emits per-chunk "chunk_stage" /
 # "chunk_restore" / "chunk_compute" spans (carrying a ``chunk`` index)
 # and reuses "flush" for the blocking finish step.
 PHASES = ("staging", "stage_batches", "state_acquire", "local_update",
           "update", "chunk_stage", "chunk_restore", "chunk_compute",
-          "flush", "eval")
+          "flush", "readback", "eval")
+
+# named scopes of the round program (module docstring)
+SCOPES = ("local_model", "local_precond", "precond_refresh",
+          "local_correction", "upload_encode", "flush", "server_update")
+
+ANNOTATION_PREFIX = "repro."
 
 
 class Tracer:
@@ -82,7 +118,8 @@ class Tracer:
     def span(self, phase: str, *, round: Optional[int] = None,
              client_id: Optional[int] = None, chunk: Optional[int] = None,
              sim_time: Optional[float] = None):
-        """Record one phase; emits a ``span`` event with the wall duration.
+        """Record one phase; emits a ``span`` event with the wall duration
+        and, under the profiler, a ``repro.<phase>`` host annotation.
 
         Disabled tracers skip the clock reads entirely — instrumented code
         paths cost nothing when nobody is listening."""
@@ -92,7 +129,8 @@ class Tracer:
             return
         t0 = self._clock()
         try:
-            yield
+            with TraceAnnotation(ANNOTATION_PREFIX + phase):
+                yield
         finally:
             self.spans += 1
             fields = {"phase": phase, "dur_s": self._clock() - t0}

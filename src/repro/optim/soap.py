@@ -117,6 +117,7 @@ def make(b1: float = 0.95, b2: float = 0.95, eps: float = 1e-8,
 
         refresh = (step % precond_freq) == 0
 
+        @jax.named_scope("precond_refresh")
         def do_refresh(args):
             ln, rn, qlo, qro = args
             qln = _eig_refresh(ln.astype(jnp.float32),

@@ -2,11 +2,10 @@
 
 One auto rule, defined once: real Pallas kernels on TPU, the interpreter
 (or the jnp reference path) everywhere else.  Transport codecs
-(``TransportConfig``/``QBlock``), the algorithm-level transport factory,
-and the kernel profiling harness all resolve their ``use_pallas`` /
-``interpret`` defaults here, so an accelerator host never silently runs
-the reference path just because a caller left the knobs at their CPU
-defaults.
+(``TransportConfig``/``QBlock``) and the algorithm-level transport
+factory resolve their ``use_pallas`` / ``interpret`` defaults here, so an
+accelerator host never silently runs the reference path just because a
+caller left the knobs at their CPU defaults.
 
 It also owns where compiled programs are cached between processes
 (``enable_compile_cache``), so every entry point shares one cache.

@@ -1,8 +1,11 @@
 """Observability subsystem: jit-pure telemetry (incl. the sync ==
 zero-staleness-async bitwise parity), tracer schema + checkpoint
-continuity, sinks, async drop events, kernel profiling hooks, and the
-BENCH_*.json document format."""
+continuity, the tracer's spans on the profiler's clock, the round
+program's named scopes, sinks, async drop events, and the BENCH_*.json
+document format."""
+import dataclasses
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,10 +22,10 @@ from repro.fed import (
 )
 from repro.fed.async_runtime.buffer import make_async_aggregate_fn
 from repro.obs import (
-    JsonlSink, MemorySink, STALENESS_BINS, StdoutRoundSink, Telemetry,
-    Tracer, attach, client_geom_dist, make_bench, staleness_histogram,
-    telemetry_dict, validate_bench, validate_event, validate_jsonl,
-    write_bench,
+    JsonlSink, MemorySink, SCOPES, STALENESS_BINS, StdoutRoundSink,
+    Telemetry, Tracer, attach, client_geom_dist, make_bench,
+    staleness_histogram, telemetry_dict, validate_bench, validate_event,
+    validate_jsonl, write_bench,
 )
 
 S, K, D, OUT = 4, 3, 16, 8
@@ -226,6 +229,39 @@ def test_checkpoint_persists_trace_identity(tmp_path):
     assert Tracer.from_state(meta.get("missing")).seq == 0
 
 
+def _profiled_spans(tracer, trace_dir):
+    """Run three nested/sequential spans under the profiler; returns the
+    ``repro.*`` events of the trace's host plane as {name: (start, end)}."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(trace_dir))
+    with tracer.span("staging", round=1):
+        with tracer.span("stage_batches", round=1):
+            jnp.ones(4).block_until_ready()
+    with tracer.span("readback", round=1):
+        pass
+    jax.profiler.stop_trace()
+    path = next(trace_dir.rglob("*.xplane.pb"))
+    host = ProfileData.from_file(str(path)).find_plane_with_name("/host:CPU")
+    return {e.name: (e.start_ns, e.start_ns + e.duration_ns)
+            for line in host.lines for e in line.events
+            if e.name.startswith("repro.")}
+
+
+def test_enabled_tracer_puts_its_spans_on_the_profiler_clock(tmp_path):
+    spans = _profiled_spans(Tracer(sinks=(MemorySink(),)), tmp_path)
+    assert sorted(spans) == ["repro.readback", "repro.stage_batches",
+                             "repro.staging"]
+    (s0, e0), (s1, e1) = spans["repro.staging"], spans["repro.stage_batches"]
+    assert s0 <= s1 < e1 <= e0                  # the child nests inside
+    assert spans["repro.readback"][0] >= e0
+
+
+def test_disabled_tracer_puts_nothing_on_the_profiler_clock(tmp_path):
+    t = Tracer()
+    assert _profiled_spans(t, tmp_path) == {}
+    assert t.spans == 3 and t.seq == 0
+
+
 # ----------------------------------------------------------------- sinks
 
 def test_stdout_sink_is_bitwise_legacy_log_round(capsys):
@@ -329,7 +365,7 @@ def test_sync_trace_golden_round(vision_problem):
     for ev in sink.events:
         validate_event(ev)
     phases = [e["phase"] for e in sink.events if e["event"] == "span"]
-    assert phases == ["staging", "update", "staging", "update"]
+    assert phases == ["stage_batches", "staging", "update", "readback"] * 2
     rounds = sink.rounds()
     assert [e["round"] for e in rounds] == [1, 2]
     tele = rounds[0]["telemetry"]
@@ -373,22 +409,29 @@ def test_async_trace_spans_drops_and_staleness(vision_problem):
     assert sum(hist) == acfg.buffer_size   # buffer's staleness, binned
 
 
-# ------------------------------------------------------ kernel profiling
+def test_round_program_names_every_scope():
+    """The compiled round program of a small FedPAC-SOAP experiment with a
+    qblock Theta upload carries each name of ``SCOPES`` in its ops'
+    ``op_name`` metadata, which is what a device trace reports."""
+    from repro.api import build_experiment
+    exp = build_experiment("fedpac_soap", scenario="cifar_like_cnn",
+                           n_clients=4, participation=0.5, local_steps=2,
+                           batch_size=4, theta_codec="qblock", seed=0)
+    cohort = exp._sample_cohort()
+    batches = exp._stage_batches(cohort)
 
-def test_profile_kernels_smoke():
-    from repro.obs.profiling import profile_kernels
-    recs = profile_kernels(shapes=((128, 128),), iters=1,
-                           kernels=("qblock", "sophia_update"))
-    assert len(recs) == 4   # 2 kernels x {ref, pallas}
-    for r in recs:
-        assert r["kind"] == "kernel"
-        assert r["kernel"] in ("qblock", "sophia_update")
-        assert r["impl"] in ("ref", "pallas")
-        assert r["us_per_call"] > 0.0
-        assert r["gflops_s"] > 0.0 and r["gbps"] > 0.0
-        assert r["shape"] == [128, 128]
-    with pytest.raises(ValueError, match="unknown kernels"):
-        profile_kernels(kernels=("bogus",))
+    def program(params, batches, key):
+        server = dataclasses.replace(exp.server, params=params)
+        server, cstate, metrics = exp.round_fn(
+            server, exp.client_state, jnp.asarray(cohort), batches, key)
+        return server.params, server.theta, server.g_global, cstate, metrics
+
+    hlo = jax.jit(program).lower(exp.server.params, batches,
+                                 jax.random.key(0)).compile().as_text()
+    op_names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for scope in SCOPES:
+        assert any(re.search(rf"(^|[/(]){scope}($|[/)])", n)
+                   for n in op_names), scope
 
 
 # ------------------------------------------------------------ BENCH docs
