@@ -45,11 +45,15 @@ class FedExperiment(abc.ABC):
                  disabled (no sinks) unless ``repro.obs.attach``-ed
       last_telemetry — the most recent jit-pure ``Telemetry`` pytree
                  (None before the first round)
+      opt      — the clients' ``repro.optim.LocalOptimizer`` (set by each
+                 runtime; None on a bare subclass)
+      server   — the server state, with the global model in ``params``
     """
 
     fed: "FedConfig"     # noqa: F821 — any config with an int .rounds
     history: list
     scenario = None      # set by repro.api.build_experiment
+    opt = None           # set by each runtime
 
     def __init__(self, fed):
         rounds = getattr(fed, "rounds", None)

@@ -10,4 +10,7 @@ sophia_update : fused momentum/clip/precondition pass (memory-bound)
 soap_rotate   : two-sided eigenbasis rotation + fused rotated Adam
 qblock        : fused blockwise int8 quantization (wire codec, memory-bound)
 fused_agg     : fused dequantize-accumulate server flush (memory-bound)
+householder_qr: QR panels factorized in VMEM inside a blocked Householder
+                QR (SOAP's eigenbasis refresh); ``ops.qr_q`` routes by
+                shape, ``ref`` is XLA's QR
 """

@@ -47,7 +47,12 @@ def attach(exp, *sinks, run_id=None) -> Tracer:
 
     ``exp`` is any ``FedExperiment``; subsequent rounds emit span/round/
     drop events into every sink.  Passing no sinks detaches (restores the
-    disabled tracer)."""
+    disabled tracer).  An experiment whose local optimizer has a curvature
+    refresh first records how each of its matrices is computed (one
+    ``refresh_routes`` event)."""
     tracer = Tracer(sinks=sinks, run_id=run_id)
     exp.tracer = tracer
+    if sinks and exp.opt is not None and exp.opt.refresh_routes is not None:
+        tracer.emit("refresh_routes", optimizer=exp.opt.name,
+                    routes=exp.opt.refresh_routes(exp.server.params))
     return tracer

@@ -22,6 +22,10 @@ Event schema (one JSON object per line under ``JsonlSink``):
   anytime_eval  metrics{...}, sim_time, round?   (continuous-traffic
                   online eval sampled by simulated time, fed.traffic)
   run_start     runtime, algorithm?, scenario?
+  refresh_routes  optimizer, routes{route: {"<m>x<m>": count}}  (once, as
+                  ``repro.obs.attach`` wires the sinks: how the local
+                  optimizer's curvature refresh computes each matrix; SOAP's
+                  QR routes are "pallas" and "xla")
 
 A disabled tracer (no sinks) is the default on every experiment: spans
 reduce to a no-op context manager and nothing is emitted, but the
@@ -66,7 +70,8 @@ from typing import Optional
 from jax.profiler import TraceAnnotation
 
 EVENT_TYPES = ("run_start", "span", "round", "client_dropped",
-               "client_join", "client_leave", "anytime_eval")
+               "client_join", "client_leave", "anytime_eval",
+               "refresh_routes")
 DROP_REASONS = ("dropout", "max_staleness", "client_left", "algo_swap")
 
 # canonical phase names; the sync runtime fuses local update, wire encode
@@ -232,6 +237,7 @@ _REQUIRED = {
     "client_leave": ("client_id", "in_flight"),
     "anytime_eval": ("metrics", "sim_time"),
     "run_start": (),
+    "refresh_routes": ("optimizer", "routes"),
 }
 
 

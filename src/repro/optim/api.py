@@ -31,6 +31,9 @@ class LocalOptimizer:
     needs_hessian: bool = False
     # Fraction/structure of Theta uploaded per round, for comm accounting.
     precond_multiplier: float = 1.0
+    # params -> {route: {"<m>x<m>": matrices}}: how the optimizer's curvature
+    # refresh computes each matrix (SOAP's QR route); None if it has none.
+    refresh_routes: Optional[Callable[[Any], dict]] = None
 
 
 def path_str(path) -> str:

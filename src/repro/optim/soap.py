@@ -14,11 +14,14 @@ fall back to AdamW.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.householder_qr import ops as qr_ops
 from repro.optim.api import LocalOptimizer, matrix_mask, as_matrix
+from repro.utils import hw
 
 
 def _tree_unzip(tree, n):
@@ -30,7 +33,9 @@ def _tree_unzip(tree, n):
 def _eig_refresh(p_mat, q, method: str = "qr"):
     """Eigenvectors(P, Q): one power iteration + orthogonalization.
 
-    method="qr"  — the paper's Alg. 4 (QR decomposition);
+    method="qr"  — the paper's Alg. 4 (QR decomposition): the blocked
+                   Householder QR of ``kernels.householder_qr`` on the TPU,
+                   XLA's QR elsewhere (``householder_qr.ops.route``);
     method="ns"  — Newton–Schulz orthogonalization of P@Q: pure matmuls,
                    MXU-aligned (beyond-paper TPU adaptation; QR lowers poorly
                    on the systolic array at large m).
@@ -42,8 +47,7 @@ def _eig_refresh(p_mat, q, method: str = "qr"):
         out = (jax.vmap(ns_ref.newton_schulz)(flat)
                if flat.ndim == 3 else ns_ref.newton_schulz(flat))
         return out.reshape(s.shape)
-    q_new, _ = jnp.linalg.qr(s)
-    return q_new
+    return qr_ops.qr_q(s)
 
 
 def _rot(g, ql, qr, inverse=False):
@@ -202,5 +206,20 @@ def make(b1: float = 0.95, b2: float = 0.95, eps: float = 1e-8,
             is_leaf=lambda x: x is None or (isinstance(x, dict) and "M" in x))
         return dict(state, mat=mat)
 
+    def refresh_routes(params):
+        """How many refresh matrices of each shape take each QR route
+        (``householder_qr.ops.route``), read off the factors ``init`` keeps:
+        {route: {"<m>x<m>": count}}, a stacked leaf counting once per
+        matrix."""
+        use = hw.default_use_pallas()
+        routes = {}
+        for f in jax.tree.leaves(get_precond(jax.eval_shape(init, params))):
+            r = routes.setdefault(qr_ops.route(f.shape, use), {})
+            key = "x".join(map(str, f.shape[-2:]))
+            r[key] = r.get(key, 0) + math.prod(f.shape[:-2])
+        return routes
+
     return LocalOptimizer("soap", init, update, get_precond, set_precond,
-                          precond_multiplier=2.0)
+                          precond_multiplier=2.0,
+                          refresh_routes=(refresh_routes if eig_method == "qr"
+                                          else None))

@@ -1,5 +1,7 @@
 """Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles,
 executed in interpret mode on CPU (deliverable c)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -10,6 +12,7 @@ from repro.kernels.sophia_update import ops as so_ops, ref as so_ref
 from repro.kernels.soap_rotate import ops as sr_ops, ref as sr_ref
 from repro.kernels.soap_rotate.kernel import adam_moments
 from repro.kernels.qblock import ops as qb_ops, ref as qb_ref
+from repro.kernels.householder_qr import ops as hq_ops, ref as hq_ref
 
 KEY = jax.random.key(7)
 
@@ -122,3 +125,44 @@ def test_adam_moments_kernel(shape):
     assert jnp.allclose(m2, m_want, atol=1e-6)
     assert jnp.allclose(v2, v_want, atol=1e-6)
     assert jnp.allclose(n, m_want / (jnp.sqrt(v_want) + 1e-8), atol=1e-5)
+
+
+# (shape, rank): rank None is a full-rank Gaussian matrix, else the
+# rank-deficient PSD G G^T of a (..., m, rank) G, as SOAP's factors are; no
+# row count is a multiple of the 128-column panel but 256
+HQR_CASES = [((40, 40), None), ((200, 200), None), ((256, 256), None),
+             ((2, 130, 100), None), ((160, 160), 60), ((3, 96, 96), 40)]
+
+
+@pytest.mark.parametrize("shape,rank", HQR_CASES)
+def test_householder_qr_matches_ref(shape, rank):
+    if rank is None:
+        s = jax.random.normal(KEY, shape, jnp.float32)
+    else:
+        g = jax.random.normal(KEY, (*shape[:-1], rank), jnp.float32)
+        s = jnp.einsum("...ik,...jk->...ij", g, g,
+                       precision=jax.lax.Precision.HIGHEST)
+    q, r = hq_ops.blocked_qr(s, interpret=True)
+    hi = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
+    eye = jnp.eye(shape[-1])
+    assert q.shape == shape and r.shape == (*shape[:-2], shape[-1], shape[-1])
+    assert float(jnp.max(jnp.abs(hi("...ki,...kj->...ij", q, q) - eye))) < 1e-5
+    assert bool(jnp.all(jnp.tril(r, -1) == 0))
+    scale = float(jnp.max(jnp.abs(s)))
+    assert float(jnp.max(jnp.abs(hi("...ik,...kj->...ij", q, r) - s))) \
+        < 1e-5 * scale
+    if rank is None:  # the same Q as XLA's, up to column signs
+        want = hq_ref.qr_q(s)
+        sign = jnp.sign(jnp.sum(q * want, axis=-2, keepdims=True))
+        assert float(jnp.max(jnp.abs(q * sign - want))) < 1e-4
+
+
+def test_householder_qr_route_and_ref_off_tpu():
+    assert hq_ops.route((8, 1536, 1536), use_pallas=True) == "pallas"
+    assert hq_ops.route((8, 1536, 1536), use_pallas=False) == "xla"
+    assert hq_ops.route((64, 64), use_pallas=True) == "xla"    # below MIN_ROWS
+    with pytest.raises(ValueError, match="square"):   # SOAP's are square
+        hq_ops.qr_q(jnp.zeros((512, 1024)))
+    s = jax.random.normal(KEY, (2, 300, 300), jnp.float32)
+    # off the TPU every shape takes XLA's QR, bitwise
+    assert jnp.array_equal(hq_ops.qr_q(s), jnp.linalg.qr(s)[0])

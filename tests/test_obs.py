@@ -380,6 +380,23 @@ def test_sync_trace_golden_round(vision_problem):
         [e["telemetry"] for e in rounds]
 
 
+def test_refresh_routes_recorded_once_per_run(vision_problem):
+    """Attaching a trace records how many of SOAP's refresh matrices of each
+    shape take each QR route, once, before any round; off the TPU every one
+    takes XLA's QR."""
+    exp, sink = _run_traced(vision_problem)
+    routes = [e for e in sink.events if e["event"] == "refresh_routes"]
+    assert len(routes) == 1 and sink.events[0] is routes[0]
+    assert routes[0]["optimizer"] == "soap"
+    assert routes[0]["routes"] == exp.opt.refresh_routes(exp.server.params)
+    assert set(routes[0]["routes"]) == {"xla"} and routes[0]["routes"]["xla"]
+    # more rounds record nothing more; detaching records nothing
+    sink.events.clear()
+    exp.run_round()
+    attach(exp)
+    assert not [e for e in sink.events if e["event"] == "refresh_routes"]
+
+
 def test_async_trace_spans_drops_and_staleness(vision_problem):
     params, loss_fn, batch_fn = vision_problem
     fed = FedConfig(algorithm="fedpac_soap", n_clients=N_CLIENTS,
@@ -395,6 +412,7 @@ def test_async_trace_spans_drops_and_staleness(vision_problem):
     exp.run()
     for ev in sink.events:
         validate_event(ev)
+    assert [e["event"] for e in sink.events].count("refresh_routes") == 1
     drops = [e for e in sink.events if e["event"] == "client_dropped"]
     # every silent counter bump is now an explicit trace event
     assert len(drops) == exp.total_dropped + exp.total_discarded

@@ -136,3 +136,64 @@ def test_soap_one_sided_for_huge_dims():
     state = opt.init(params)
     st = state["mat"]["layer"]["w"]
     assert "L" not in st and "R" in st  # 64 > 32 -> left side skipped
+
+
+def _well_conditioned(key, shape):
+    """Orthogonal times singular values in [1, 2]: full-rank SOAP factors,
+    so the refresh's Q (up to column signs, which the step-0 direction does
+    not see) is well determined."""
+    q = jnp.linalg.qr(jax.random.normal(key, shape))[0]
+    return q * jnp.linspace(1.0, 2.0, shape[-1])
+
+
+def test_soap_refresh_kernel_path_matches_ref(monkeypatch):
+    from repro.kernels.householder_qr import ops as hq_ops
+    from repro.utils import hw
+    k1, k2, k3 = jax.random.split(KEY, 3)
+    params = {"attn": {"wo": jnp.zeros((64, 64))},
+              "stack": jnp.zeros((2, 40, 40)), "b": jnp.zeros((40,))}
+    grads = {"attn": {"wo": _well_conditioned(k1, (64, 64))},
+             "stack": _well_conditioned(k2, (2, 40, 40)),
+             "b": jax.random.normal(k3, (40,))}
+    opt = optim.make("soap")
+    state = opt.init(params)          # step 0: Q = I, M = V = 0
+    want, _ = opt.update(grads, state, params, 0)
+
+    # steer the refresh onto the kernel path (interpret mode off the TPU)
+    calls = []
+    blocked = hq_ops.blocked_qr
+    monkeypatch.setattr(hw, "default_use_pallas", lambda: True)
+    monkeypatch.setattr(hq_ops, "MIN_ROWS", 8)
+    monkeypatch.setattr(hq_ops, "blocked_qr",
+                        lambda s, **kw: calls.append(s.shape) or
+                        blocked(s, **kw))
+    got, _ = opt.update(grads, state, params, 0)
+    assert sorted(calls) == [(2, 40, 40), (2, 40, 40), (64, 64), (64, 64)]
+    assert opt.refresh_routes(params) == {"pallas": {"64x64": 2,
+                                                     "40x40": 4}}
+    for w, g in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        assert float(jnp.max(jnp.abs(w - g))) < 1e-5
+
+
+def test_soap_refresh_routes_count_every_matrix(monkeypatch):
+    from repro import configs
+    from repro.models import model as M, vision
+    from repro.utils import hw
+    opt = optim.make("soap")
+    # a small ViT: per block wqkv 64|192, wo 64|64, w1 64|256, w2 256|64
+    params, _ = vision.init_vit(KEY, d_model=64, layers=2)
+    assert opt.refresh_routes(params) == {
+        "xla": {"64x64": 10, "192x192": 2, "256x256": 4}}
+    monkeypatch.setattr(hw, "default_use_pallas", lambda: True)  # TPU rule
+    assert opt.refresh_routes(params) == {
+        "xla": {"64x64": 10, "192x192": 2}, "pallas": {"256x256": 4}}
+    # the chip benchmark's cells route every refresh matrix to the kernel
+    vit_s = jax.eval_shape(lambda k: vision.init_vit(
+        k, image_size=32, patch=4, d_model=384, layers=12, heads=6,
+        n_classes=100)[0], KEY)
+    assert opt.refresh_routes(vit_s) == {
+        "pallas": {"384x384": 60, "1152x1152": 12, "1536x1536": 24}}
+    smollm = M.param_shapes(configs.get_config("smollm-360m").replace(
+        num_layers=4))
+    assert opt.refresh_routes(smollm) == {
+        "pallas": {"960x960": 36, "320x320": 8, "2560x2560": 12}}

@@ -1,5 +1,9 @@
-"""Compile guards: the five Pallas kernels compiled (not interpreted) for a
-described TPU v5e at llama-60m widths (d_model 512, d_ff 1376).
+"""Compile guards: the Pallas kernels compiled (not interpreted) for a
+described TPU v5e: five at llama-60m widths (d_model 512, d_ff 1376), and
+the panel kernel of SOAP's blocked Householder QR at the chip benchmark's
+widest refresh matrices (ViT-S: 8 clients of 1,536 rows; SmolLM-360M: 4
+stacked layers of 2,560 rows), with the blocked QR around it at three
+panels.
 
 No chip is needed: the TPU compiler compiles for a topology that is only
 described.  The description happens inside the module fixture, never at
@@ -12,6 +16,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.fused_agg.kernel import dequant_accumulate
+from repro.kernels.householder_qr import ops as hq_ops
+from repro.kernels.householder_qr.kernel import factor_panel
 from repro.kernels.ns_ortho.kernel import matmul_fused
 from repro.kernels.qblock.kernel import quantize
 from repro.kernels.soap_rotate.kernel import adam_moments
@@ -74,3 +80,18 @@ def test_ns_ortho_matmul_fused_compiles(one_chip):
 def test_sophia_update_compiles(one_chip):
     g, m, h = (_spec(one_chip, (D_MODEL, D_FF)) for _ in range(3))
     assert "tpu_custom_call" in _compiled_text(sophia_update, g, m, h)
+
+
+@pytest.mark.parametrize("batch,rows", [(8, 1536), (4, 2560)])
+def test_householder_qr_panel_compiles(one_chip, batch, rows):
+    panel = _spec(one_chip, (batch, hq_ops.PANEL, rows))
+    assert "tpu_custom_call" in _compiled_text(factor_panel, panel)
+
+
+def test_householder_qr_blocked_compiles(one_chip):
+    # three panels: the trailing update and the backward Q formation of
+    # every panel position; the panel kernel's widest shapes are above
+    shape = (2, 3 * hq_ops.PANEL, 3 * hq_ops.PANEL)
+    assert hq_ops.route(shape, use_pallas=True) == "pallas"
+    assert "tpu_custom_call" in _compiled_text(hq_ops.blocked_qr,
+                                               _spec(one_chip, shape))
