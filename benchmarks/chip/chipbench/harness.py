@@ -1,7 +1,10 @@
 """One run of one cell: build, warm up and check, measure, compare.
 
-The run builds the experiment through the system's normal path
-(``repro.api.build_experiment`` on a registered scenario source), then:
+A cell runs the algorithm its mix names (``algorithm``: any registered one,
+``fedpac_soap``, ``fedpac_muon``, ``fedavg``, ...), its local optimizer set
+from the mix's block named after that optimizer (``soap``, ``muon``,
+``sgd``, ...).  The run builds the experiment through the system's normal
+path (``repro.api.build_experiment`` on a registered scenario source), then:
 
   set-up   the first ``check_rounds`` rounds go through the window's own call
            (``FederatedExperiment.run_round``) on the window's own feed; they
@@ -107,18 +110,30 @@ def set_precision(name: str) -> None:
     jax.config.update("jax_default_matmul_precision", name)
 
 
+def optimizer_kwargs(traffic: dict) -> dict:
+    """The local optimizer's settings: the mix's block named after the
+    optimizer its algorithm runs (``soap``, ``muon``, ``sgd``, ...), whole."""
+    from repro.api import resolve
+    name = resolve(traffic["algorithm"]).optimizer
+    if name not in traffic:
+        raise KeyError(f"the mix of algorithm {traffic['algorithm']!r} has "
+                       f"no {name!r} block, the settings of its optimizer")
+    return dict(traffic[name])
+
+
 def build(cell: S.Cell, seed: int, ref, binding, precision=None):
-    """The experiment, through the system's normal path: parameters in the
-    dtype the configuration states, products at its precision unless
-    ``precision`` names another (a control run), data from the mix's fixed
-    data seed, weights and every sampling draw from ``seed``."""
+    """The experiment, through the system's normal path: the algorithm the
+    mix names, its optimizer set from the mix's block of that name,
+    parameters in the dtype the configuration states, products at its
+    precision unless ``precision`` names another (a control run), data from
+    the mix's fixed data seed, weights and every sampling draw from
+    ``seed``."""
     from repro.api import build_experiment
     set_precision(precision or cell.config["matmul_precision"])
     t = cell.traffic
+    opt = optimizer_kwargs(t)
     dtype = jnp.dtype(cell.config[binding.DTYPE_KEY])
     spec = binding.scenario(cell.config, t, ref, dtype, seed)
-    opt = {k: t["soap"][k] for k in ("b1", "b2", "eps", "precond_freq",
-                                     "adam_b1", "adam_b2")}
     return build_experiment(
         t["algorithm"], scenario=spec, scenario_seed=t["data"]["seed"],
         opt_kwargs=opt, seed=seed, n_clients=t["n_clients"],

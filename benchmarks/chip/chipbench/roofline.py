@@ -11,9 +11,11 @@ from chipbench import flops as F
 from chipbench.xtrace import kernel_name
 
 
-def kernel_share(ctx, kernel: str, function: str) -> float | None:
+def kernel_share(ctx, kernel: str, function: str,
+                 clients: int | None = None) -> float | None:
     """``function``: the name of the Python function whose ``pallas_call``
-    the kernel is (its ops are named after it)."""
+    the kernel is (its ops are named after it); ``clients``: how many of the
+    cohort's clients one call covers, the whole cohort unless given."""
     if ctx.traffic.get("theta_codec") != "qblock" or not ctx.theta_sizes:
         return None
     events = [e for e in ctx.reduced.ops
@@ -24,7 +26,7 @@ def kernel_share(ctx, kernel: str, function: str) -> float | None:
     if calls < 1 or calls != int(calls):
         return None       # the events do not map onto the leaves
     least = ctx.rounds * calls * F.kernel_least_seconds(
-        kernel, ctx.theta_sizes, ctx.traffic["qblock_size"], ctx.clients,
-        ctx.peaks)
+        kernel, ctx.theta_sizes, ctx.traffic["qblock_size"],
+        clients or ctx.clients, ctx.peaks)
     spent = sum(e.dur_ns for e in events) * 1e-9
     return 100.0 * least / spent

@@ -1,4 +1,8 @@
-"""Chip benchmark of federated FedPAC-SOAP rounds: one run of one cell.
+"""Chip benchmark of federated training rounds: one run of one cell.
+
+A cell runs the algorithm its traffic mix names (``fedpac_soap``,
+``fedavg``, ...), its local optimizer set from the mix's block named after
+that optimizer (``soap``, ``sgd``, ...).
 
     python3 benchmarks/chip/run.py --workload vit_s16.c8_k10_qblock \\
         --seed 1234 --seconds 20 --trace 0

@@ -1,10 +1,10 @@
 """Tiny cells for the CPU tests: a benchmark tree in a temporary directory.
 
 ``make_tree(root)`` writes ``BENCHMARK.json`` and a ``bench/`` directory with
-one tiny ViT and one tiny Llama configuration, their mixes, their limits, and
-copies of the real per-layer metric readers, so that ``spec.load_cell``
-finds everything by name there.  The families' references and bindings are
-the real ones.
+one tiny ViT and one tiny Llama configuration, their mixes (FedPAC-SOAP on
+both, FedAvg on the ViT too), their limits, and copies of the real
+per-layer metric readers, so that ``spec.load_cell`` finds everything by
+name there.  The families' references and bindings are the real ones.
 """
 from __future__ import annotations
 
@@ -60,11 +60,19 @@ TINY_LM_MIX = {
     "data": {"seed": 17, "n_docs": 32, "tokens_per_doc": 64, "n_topics": 4,
              "n_eval_docs": 2, "eval_batch": 2}, "check_rounds": 2}
 
+# FedAvg on the tiny ViT: local SGD at the paper's SGD rate, no Theta
+TINY_FEDAVG_MIX = dict(
+    {k: v for k, v in TINY_VIT_MIX.items() if k != "soap"},
+    algorithm="fedavg", lr=0.1, beta=0.0, sgd={"momentum": 0.0},
+    theta_codec="dense")
+
 # float32 on the CPU against a float32 reference at the highest precision
 LIMITS = {"loss": 1e-3, "grad": 1e-2, "theta": 1e-2, "delta": 1e-2}
 
 CELLS = {"tiny_vit.q": ("tiny-vit", "tiny_q", TINY_VIT, TINY_VIT_MIX),
-         "tiny_lm.d": ("tiny-lm", "tiny_d", TINY_LM, TINY_LM_MIX)}
+         "tiny_lm.d": ("tiny-lm", "tiny_d", TINY_LM, TINY_LM_MIX),
+         "tiny_vit.avg": ("tiny-vit", "tiny_avg", TINY_VIT,
+                          TINY_FEDAVG_MIX)}
 
 
 def _dump(path, obj):
@@ -111,6 +119,10 @@ def make_tree(root: str, limits=None, dtype: str = "float32") -> str:
 # and every planted fault and the bfloat16 control fail
 TEST_LIMITS = {"loss": 5e-3, "grad": 0.08, "theta": 0.05, "delta": 0.08}
 TEST_SEED = 5
+# the same for the tiny FedAvg cell (largest sound readings on seeds 5-7:
+# grad 3.9e-7, delta 9.1e-7; smallest of the bfloat16 control: grad 0.049,
+# delta 0.092; of the half-batch fault: grad 0.36, delta 0.38)
+FEDAVG_TEST_LIMITS = {"loss": 1e-4, "grad": 1e-3, "delta": 1e-3}
 
 
 def run_tiny(root: str, cell_name: str, fault=None) -> dict:
